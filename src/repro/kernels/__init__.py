@@ -6,6 +6,6 @@
   bin_stats    — fused per-bin count/sum/sumsq (OBR Eq. 10 + oscillation)
 
 Written against BlockSpec VMEM tiling for TPU; validated on CPU via
-interpret=True (ops.on_tpu() switches automatically).
+interpret=True (platform.resolve_interpret switches automatically).
 """
 from repro.kernels import ops, ref  # noqa: F401

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import resolve_interpret
+
 DEFAULT_BLOCK = (512, 128)
 
 
@@ -46,7 +48,7 @@ def _bin_stats_kernel(w_ref, s_ref, o_ref, acc_ref, *, q_n, q_p, n_bins, n_steps
 
 @functools.partial(jax.jit, static_argnames=("q_n", "q_p", "block", "interpret"))
 def bin_stats_2d(w, scale, *, q_n: int, q_p: int, block=DEFAULT_BLOCK,
-                 interpret: bool = True):
+                 interpret=None):
     """w: (M, N) with per-tensor scale () -> (3, n_bins) [count, sum, sumsq]."""
     m, n = w.shape
     n_bins = q_n + q_p + 1
@@ -64,5 +66,5 @@ def bin_stats_2d(w, scale, *, q_n: int, q_p: int, block=DEFAULT_BLOCK,
         out_specs=pl.BlockSpec((3, n_bins), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((3, n_bins), jnp.float32),
         scratch_shapes=[pltpu.VMEM((3, n_bins), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(w, s2)

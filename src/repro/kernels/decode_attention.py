@@ -32,15 +32,16 @@ set to the finite NEG_INF, so a fully-masked row (idle serving slot)
 degrades to the same uniform-weights junk the fallback's softmax produces
 instead of NaN.
 
-Grid/residency notes (for the interpret=False TPU validation pass, see
-ROADMAP "Open items"): grid = (batch, kv_tiles) with the KV-tile axis
+Grid/residency notes: grid = (batch, kv_tiles) with the KV-tile axis
 innermost; each output block is indexed by batch only, so its revisits are
 consecutive — but the kernel still accumulates in persistent VMEM scratch
 and writes each output exactly once on the final tile, the pattern that is
 legal regardless of output-block residency. Lane alignment pads head_dim to
-128 and the KV tile to >= 8 sublanes; the (1, G)/(1, bt) int32 position
-blocks and the Hkv-sized block axes are NOT tiled to (8, 128) and rely on
-Mosaic relayout on real hardware.
+128 and the KV tile to >= 8 sublanes. The int32 positions ride as
+(B, 1, T) and (B, G, 1) arrays so their last two block dims are (1, bt) and
+(G, 1): each equals the array's dim or is (8, 128)-tiled, as the TPU
+lowering requires (tests/test_tpu_compile.py compiles every KV layout for a
+v5e chip).
 """
 from __future__ import annotations
 
@@ -50,6 +51,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 NEG_INF = -2.0e9  # matches models/attention.py: finite, exp() underflows to 0
 LANE = 128
@@ -61,14 +64,28 @@ def _round_up(x: int, m: int) -> int:
 
 
 def _unpack_nibbles(packed: jax.Array) -> jax.Array:
-    """(..., P) int8 bytes -> (..., 2P) int4 codes (quantizer.pack_int4
-    interleave: byte p = code 2p low nibble, code 2p+1 high, two's
-    complement). Shift-based sign extension, same idiom as int4_matmul."""
+    """(..., P) int8 bytes -> (..., 2P) int4 codes, DE-interleaved: the low
+    nibbles (codes 0, 2, 4, ...) then the high ones (1, 3, 5, ...).
+    quantizer.pack_int4 stores byte p = code 2p low, code 2p+1 high, two's
+    complement; shift-based sign extension as in int4_matmul. Re-interleaving
+    along lanes inside the kernel does not compile for the TPU, so the
+    wrapper permutes q's head_dim the same way and un-permutes the output."""
     p32 = packed.astype(jnp.int32)
     lo = (p32 << 28) >> 28
     hi = (p32 << 24) >> 28
-    st = jnp.stack([lo, hi], axis=-1)  # (..., P, 2)
-    return st.reshape(*packed.shape[:-1], packed.shape[-1] * 2)
+    return jnp.concatenate([lo, hi], axis=-1)
+
+
+def _deinterleave(x: jax.Array) -> jax.Array:
+    """(..., 2P) -> (..., 2P) with even entries first, then odd."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
+def _reinterleave(x: jax.Array) -> jax.Array:
+    """Inverse of _deinterleave."""
+    half = x.shape[-1] // 2
+    st = jnp.stack([x[..., :half], x[..., half:]], axis=-1)
+    return st.reshape(x.shape)
 
 
 def _flash_decode_kernel(*refs, quantized: bool, packed: bool, window: int,
@@ -88,8 +105,8 @@ def _flash_decode_kernel(*refs, quantized: bool, packed: bool, window: int,
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
     q = q_ref[0]                     # (Hkv, G, D), already pre-scaled
-    kv_pos = pos_ref[0]              # (bt,) int32
-    q_pos = qpos_ref[0]              # (G,) int32
+    kv_pos = pos_ref[0]              # (1, bt) int32
+    q_pos = qpos_ref[0]              # (G, 1) int32
 
     if quantized:
         kc, vc = k_ref[0], v_ref[0]  # (bt, Hkv, D or D/2) int codes
@@ -111,9 +128,9 @@ def _flash_decode_kernel(*refs, quantized: bool, packed: bool, window: int,
     s = s.astype(jnp.float32)
     if softcap > 0.0:
         s = softcap * jnp.tanh(s / softcap)
-    valid = (kv_pos[None, :] >= 0) & (kv_pos[None, :] <= q_pos[:, None])
+    valid = (kv_pos >= 0) & (kv_pos <= q_pos)          # (G, bt)
     if window > 0:
-        valid &= kv_pos[None, :] > (q_pos[:, None] - window)
+        valid &= kv_pos > (q_pos - window)
     s = jnp.where(valid[None, :, :], s, NEG_INF)  # (Hkv, G, bt)
 
     m_prev = m_scr[...]              # (Hkv, G)
@@ -153,9 +170,7 @@ def pooled_decode_attention(q, k_store, v_store, k_scale, v_scale, kv_pos,
     (B, C, H) f32 running max / sum. out = acc / l; to merge extra keys,
     continue the online softmax with (m, l, acc).
     """
-    if interpret is None:
-        from repro.kernels.ops import on_tpu
-        interpret = not on_tpu()
+    interpret = resolve_interpret(interpret)
     b, c, h, d = q.shape
     assert h % q_per_kv == 0, (h, q_per_kv)
     hkv = h // q_per_kv
@@ -182,6 +197,8 @@ def pooled_decode_attention(q, k_store, v_store, k_scale, v_scale, kv_pos,
     dsp = dp // 2 if packed else dp
 
     q5 = jnp.pad(q5, ((0, 0), (0, 0), (0, gp - g), (0, dp - d)))
+    if packed:  # match the kernel's de-interleaved unpack order
+        q5 = _deinterleave(q5)
     qp = jnp.pad(qp, ((0, 0), (0, gp - g)), constant_values=-1)
     ds = k_store.shape[-1]
     k_store = jnp.pad(k_store, ((0, 0), (0, tp - t), (0, 0), (0, dsp - ds)))
@@ -205,9 +222,13 @@ def pooled_decode_attention(q, k_store, v_store, k_scale, v_scale, kv_pos,
                          ((0, 0), (0, tp - t), (0, 0))),
                  jnp.pad(v_scale[..., 0].astype(jnp.float32),
                          ((0, 0), (0, tp - t), (0, 0)))]
-    in_specs += [pl.BlockSpec((1, bt), lambda bb, tt: (bb, tt)),
-                 pl.BlockSpec((1, gp), lambda bb, tt: (bb, 0))]
-    args += [kv_pos, qp]
+    # positions carry a singleton axis so the last two block dims are
+    # (1, bt) / (gp, 1): each either equals the array's dim or is tiled
+    # (bt % 128, gp % 8) — the TPU block-shape rule. q_pos arrives as a
+    # column so the mask broadcasts without an in-kernel transpose.
+    in_specs += [pl.BlockSpec((1, 1, bt), lambda bb, tt: (bb, 0, tt)),
+                 pl.BlockSpec((1, gp, 1), lambda bb, tt: (bb, 0, 0))]
+    args += [kv_pos[:, None, :], qp[:, :, None]]
 
     acc, m, l = pl.pallas_call(
         kern,
@@ -230,6 +251,8 @@ def pooled_decode_attention(q, k_store, v_store, k_scale, v_scale, kv_pos,
     )(*args)
 
     # slice padding away and restore (B, C, H, ...) layout
+    if packed:
+        acc = _reinterleave(acc)
     acc = acc[:, :, :g, :d].reshape(b, hkv, c, q_per_kv, d)
     acc = acc.transpose(0, 2, 1, 3, 4).reshape(b, c, h, d)
     m = m[:, :, :g].reshape(b, hkv, c, q_per_kv).transpose(0, 2, 1, 3)

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import resolve_interpret
+
 DEFAULT_BLOCK = (256, 512)
 
 
@@ -46,7 +48,7 @@ def _fq_kernel_rows(x_ref, s_ref, o_ref, *, q_n, q_p):
 
 @functools.partial(jax.jit, static_argnames=("q_n", "q_p", "block", "interpret"))
 def fake_quant_2d(x, scale, offset=None, *, q_n: int, q_p: int,
-                  block=DEFAULT_BLOCK, interpret: bool = True):
+                  block=DEFAULT_BLOCK, interpret=None):
     """Per-tensor fake-quant of a 2D array. scale/offset: () scalars."""
     m, n = x.shape
     bm = min(block[0], m)
@@ -65,13 +67,13 @@ def fake_quant_2d(x, scale, offset=None, *, q_n: int, q_p: int,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, s2, b2)
 
 
 @functools.partial(jax.jit, static_argnames=("q_n", "q_p", "block", "interpret"))
 def fake_quant_rows(x, row_scale, *, q_n: int, q_p: int,
-                    block=DEFAULT_BLOCK, interpret: bool = True):
+                    block=DEFAULT_BLOCK, interpret=None):
     """Row-grouped fake-quant: x (M, N), row_scale (M, 1) — heads/experts on
     rows (MDQ granularity)."""
     m, n = x.shape
@@ -87,5 +89,5 @@ def fake_quant_rows(x, row_scale, *, q_n: int, q_p: int,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, row_scale.astype(jnp.float32))

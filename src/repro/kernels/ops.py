@@ -2,7 +2,7 @@
 
 Handle arbitrary-rank tensors (reshape to 2D, pad to tile multiples, unpad),
 QuantSpec plumbing, and the interpret flag (True on CPU; False on real TPU —
-`on_tpu()` picks automatically).
+`platform.resolve_interpret` picks automatically).
 
 `fused_qat_matmul` is the differentiable entry point: a jax.custom_vjp whose
 forward AND backward are single Pallas kernels (one HBM round trip each —
@@ -29,10 +29,7 @@ from repro.core.quantizer import QuantSpec
 from repro.kernels import bin_stats as _bs
 from repro.kernels import fake_quant as _fq
 from repro.kernels import quant_matmul as _qmm
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels.platform import on_tpu, resolve_interpret  # noqa: F401 (on_tpu: dispatch in models/)
 
 
 def _pad2d(x, bm, bn):
@@ -46,7 +43,6 @@ def _pad2d(x, bm, bn):
 
 def fake_quant(x, scale, spec: QuantSpec, offset=None, *, interpret=None):
     """Per-tensor fake-quant of an arbitrary-rank tensor (scalar scale)."""
-    interpret = (not on_tpu()) if interpret is None else interpret
     shape = x.shape
     x2 = x.reshape(-1, shape[-1]) if x.ndim > 1 else x.reshape(1, -1)
     bm, bn = _fq.DEFAULT_BLOCK
@@ -58,7 +54,6 @@ def fake_quant(x, scale, spec: QuantSpec, offset=None, *, interpret=None):
 
 def fake_quant_grouped(x, group_scale, spec: QuantSpec, *, interpret=None):
     """Row-grouped fake-quant: x (G, ...) with scale (G,) — per-head/expert."""
-    interpret = (not on_tpu()) if interpret is None else interpret
     g = x.shape[0]
     x2 = x.reshape(g, -1)
     bm, bn = _fq.DEFAULT_BLOCK
@@ -73,7 +68,6 @@ def fake_quant_grouped(x, group_scale, spec: QuantSpec, *, interpret=None):
 def quant_matmul(x, w, a_scale, a_offset, w_scale, a_spec: QuantSpec,
                  w_spec: QuantSpec, *, interpret=None, out_dtype=jnp.float32):
     """Fused q(x) @ q(w). x (..., K), w (K, N); w_scale () or (N,)."""
-    interpret = (not on_tpu()) if interpret is None else interpret
     lead = x.shape[:-1]
     k = x.shape[-1]
     n = w.shape[-1]
@@ -99,7 +93,6 @@ def int_matmul(x, w_codes, w_scale, w_spec: QuantSpec, *, packed: bool = False,
     packed=True:  w_codes (K//2, N) int8 nibble-packed int4 pairs (see
     core.quantizer.pack_int4) — 0.5 byte/weight, unpacked tile-wise in VMEM.
     """
-    interpret = (not on_tpu()) if interpret is None else interpret
     lead = x.shape[:-1]
     k = x.shape[-1]
     n = w_codes.shape[-1]
@@ -207,7 +200,7 @@ def fused_qat_matmul(x, w2, a_scale, a_offset, ws_vec,
     factors ride on autodiff outside this boundary.
     """
     assert w_scale_axis in ("n", "k"), w_scale_axis
-    interpret = (not on_tpu()) if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     static = (a_spec.q_n, a_spec.q_p, w_spec.q_n, w_spec.q_p,
@@ -294,7 +287,7 @@ def fused_qat_matmul_batched(x3, w3, a_scale, a_offset, ws_en,
     differentiable broadcast). Forward and backward are each ONE Pallas
     kernel whose grid leads with the expert axis.
     """
-    interpret = (not on_tpu()) if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     static = (a_spec.q_n, a_spec.q_p, w_spec.q_n, w_spec.q_p,
               bool(interpret), out_dtype, bool(cotangent_rounding))
     return _fused_qmm3d(static, x3, w3, a_scale, a_offset, ws_en)
@@ -302,7 +295,6 @@ def fused_qat_matmul_batched(x3, w3, a_scale, a_offset, ws_en,
 
 def bin_stats(w, scale, spec: QuantSpec, *, interpret=None):
     """(count, sum, sumsq) per bin for a per-tensor-scaled weight tensor."""
-    interpret = (not on_tpu()) if interpret is None else interpret
     w2 = w.reshape(-1, w.shape[-1]) if w.ndim > 1 else w.reshape(1, -1)
     # rows must tile evenly; pad rows with values far outside the clip range
     # is wrong (they'd land in edge bins) — instead pad with the scale value

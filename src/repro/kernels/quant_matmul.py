@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import resolve_interpret
+
 DEFAULT_TILES = (128, 128, 512)  # (bm, bn, bk) — MXU-aligned
 
 # VMEM ceiling for the combined backward's scratch accumulators (its dW row
@@ -80,7 +82,7 @@ def _w_scale_spec(w_scale, bk, bn):
                                              "tiles", "interpret", "out_dtype"))
 def quant_matmul(x, w, a_scale, a_offset, w_scale, *,
                  q_n_a: int, q_p_a: int, q_n_w: int, q_p_w: int,
-                 tiles=DEFAULT_TILES, interpret: bool = True,
+                 tiles=DEFAULT_TILES, interpret=None,
                  out_dtype=jnp.float32):
     """x: (M, K); w: (K, N); a_scale/a_offset: scalars; w_scale: (1, N)
     column groups or (K, 1) row groups (K-side per-head scales)."""
@@ -107,8 +109,18 @@ def quant_matmul(x, w, a_scale, a_offset, w_scale, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w, a_s, a_b, w_scale.astype(jnp.float32))
+
+
+def _expert_operands(a_scale, a_offset, w_scale):
+    """(E, 1) scale/offset -> (E, 1, 1) and (E, N) column scales ->
+    (E, 1, N): a singleton sublane axis makes each per-expert block's last
+    two dims equal the array's, as the TPU block-shape rule requires."""
+    e = a_scale.shape[0]
+    return (a_scale.astype(jnp.float32).reshape(e, 1, 1),
+            a_offset.astype(jnp.float32).reshape(e, 1, 1),
+            w_scale.astype(jnp.float32).reshape(e, 1, -1))
 
 
 def _qmm_batched_kernel(x_ref, w_ref, as_ref, ab_ref, ws_ref, o_ref, acc_ref,
@@ -118,13 +130,13 @@ def _qmm_batched_kernel(x_ref, w_ref, as_ref, ab_ref, ws_ref, o_ref, acc_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[0].astype(jnp.float32)
-    a_s = jnp.maximum(as_ref[0, 0], 1e-9)
-    a_b = ab_ref[0, 0]
+    a_s = jnp.maximum(as_ref[0, 0, 0], 1e-9)
+    a_b = ab_ref[0, 0, 0]
     xq = jnp.clip(jnp.round((x - a_b) / a_s), -float(q_n_a), float(q_p_a))
     xd = xq * a_s + a_b
 
     w = w_ref[0].astype(jnp.float32)
-    w_s = jnp.maximum(ws_ref[...].astype(jnp.float32), 1e-9)  # (1, bn)
+    w_s = jnp.maximum(ws_ref[0].astype(jnp.float32), 1e-9)  # (1, bn)
     wq = jnp.clip(jnp.round(w / w_s), -float(q_n_w), float(q_p_w))
     wd = wq * w_s
 
@@ -140,7 +152,7 @@ def _qmm_batched_kernel(x_ref, w_ref, as_ref, ab_ref, ws_ref, o_ref, acc_ref,
                                              "tiles", "interpret", "out_dtype"))
 def quant_matmul_batched(x, w, a_scale, a_offset, w_scale, *,
                          q_n_a: int, q_p_a: int, q_n_w: int, q_p_w: int,
-                         tiles=DEFAULT_TILES, interpret: bool = True,
+                         tiles=DEFAULT_TILES, interpret=None,
                          out_dtype=jnp.float32):
     """Batched-expert fused matmul: out[e] = q_a(x[e]) @ q_w(w[e]).
 
@@ -162,16 +174,15 @@ def quant_matmul_batched(x, w, a_scale, a_offset, w_scale, *,
         in_specs=[
             pl.BlockSpec((1, bm, bk), lambda ee, i, j, kk: (ee, i, kk)),
             pl.BlockSpec((1, bk, bn), lambda ee, i, j, kk: (ee, kk, j)),
-            pl.BlockSpec((1, 1), lambda ee, i, j, kk: (ee, 0)),
-            pl.BlockSpec((1, 1), lambda ee, i, j, kk: (ee, 0)),
-            pl.BlockSpec((1, bn), lambda ee, i, j, kk: (ee, j)),
+            pl.BlockSpec((1, 1, 1), lambda ee, i, j, kk: (ee, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda ee, i, j, kk: (ee, 0, 0)),
+            pl.BlockSpec((1, 1, bn), lambda ee, i, j, kk: (ee, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda ee, i, j, kk: (ee, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(x, w, a_scale.astype(jnp.float32), a_offset.astype(jnp.float32),
-      w_scale.astype(jnp.float32))
+        interpret=resolve_interpret(interpret),
+    )(x, w, *_expert_operands(a_scale, a_offset, w_scale))
 
 
 # ---------------------------------------------------------------------------
@@ -191,17 +202,18 @@ def quant_matmul_batched(x, w, a_scale, a_offset, w_scale, *,
 #
 # Cotangents are rounded through bf16 after the f32-accumulated dot so the
 # fused path is bit-compatible with the unfused bf16 einsum's autodiff.
+#
+# d s_a and d b_a are sums over all of X. The TPU cannot store a scalar to
+# VMEM, and a (1, 1) block revisited by every grid step would also depend on
+# output-block residency. So each dX tile writes its column sums once, as a
+# lane-dense (1, bk) row of an (M-tiles, 1, K) partials array, and the
+# wrapper sums the partials.
 
 
 def _qmm_dx_kernel(dy_ref, w_ref, ws_ref, x_ref, as_ref, ab_ref,
                    dx_ref, dsa_ref, dba_ref, acc_ref, *,
                    q_n_a, q_p_a, q_n_w, q_p_w, n_n, round_cot):
-    i, kk, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    @pl.when(jnp.logical_and(i == 0, jnp.logical_and(kk == 0, j == 0)))
-    def _init_scalars():
-        dsa_ref[...] = jnp.zeros_like(dsa_ref)
-        dba_ref[...] = jnp.zeros_like(dba_ref)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -232,8 +244,8 @@ def _qmm_dx_kernel(dy_ref, w_ref, ws_ref, x_ref, as_ref, ab_ref,
                              u <= float(q_p_a)).astype(jnp.float32)
         q = jnp.clip(jnp.round(u), -float(q_n_a), float(q_p_a))
         dx_ref[...] = (dxd * mf).astype(dx_ref.dtype)
-        dsa_ref[0, 0] += jnp.sum(dxd * (q - mf * u))
-        dba_ref[0, 0] += jnp.sum(dxd * (1.0 - mf))
+        dsa_ref[0] = jnp.sum(dxd * (q - mf * u), axis=0, keepdims=True)
+        dba_ref[0] = jnp.sum(dxd * (1.0 - mf), axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("q_n_a", "q_p_a", "q_n_w", "q_p_w",
@@ -241,7 +253,7 @@ def _qmm_dx_kernel(dy_ref, w_ref, ws_ref, x_ref, as_ref, ab_ref,
 def quant_matmul_dx(dy, x, w, a_scale, a_offset, w_scale, *,
                     q_n_a: int, q_p_a: int, q_n_w: int, q_p_w: int,
                     round_cot: bool = True,
-                    tiles=DEFAULT_TILES, interpret: bool = True):
+                    tiles=DEFAULT_TILES, interpret=None):
     """Backward wrt x of quant_matmul: (dX, d a_scale_raw, d a_offset_raw).
 
     dy: (M, N); x: (M, K); w: (K, N); w_scale: (1, N) column groups or
@@ -278,18 +290,18 @@ def quant_matmul_dx(dy, x, w, a_scale, a_offset, w_scale, *,
         ],
         out_specs=[
             pl.BlockSpec((bm, bk), lambda i, kk, j: (i, kk)),
-            pl.BlockSpec((1, 1), lambda i, kk, j: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, kk, j: (0, 0)),
+            pl.BlockSpec((1, 1, bk), lambda i, kk, j: (i, 0, kk)),
+            pl.BlockSpec((1, 1, bk), lambda i, kk, j: (i, 0, kk)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, k), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], 1, k), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(dy, w, w_scale.astype(jnp.float32), x, a_s, a_b)
-    return dx, dsa.reshape(()), dba.reshape(())
+    return dx, jnp.sum(dsa), jnp.sum(dba)
 
 
 def _qmm_dw_kernel(x_ref, dy_ref, as_ref, ab_ref, w_ref, ws_ref,
@@ -361,7 +373,7 @@ def _qmm_dw_kernel(x_ref, dy_ref, as_ref, ab_ref, w_ref, ws_ref,
 def quant_matmul_dw(dy, x, w, a_scale, a_offset, w_scale, *,
                     q_n_a: int, q_p_a: int, q_n_w: int, q_p_w: int,
                     round_cot: bool = True,
-                    tiles=DEFAULT_TILES, interpret: bool = True):
+                    tiles=DEFAULT_TILES, interpret=None):
     """Backward wrt w of quant_matmul: (dW, d w_scale_raw).
 
     w_scale (1, N) column groups -> dws (1, N), the per-column cotangent
@@ -411,7 +423,7 @@ def quant_matmul_dw(dy, x, w, a_scale, a_offset, w_scale, *,
             jax.ShapeDtypeStruct(dws_shape, jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32), dws_scratch],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, dy, a_s, a_b, w, w_scale.astype(jnp.float32))
     return dw, dws
 
@@ -457,11 +469,6 @@ def _qmm_bwd_kernel(dy_ref, x_ref, w_ref, as_ref, ab_ref, ws_ref,
                     k_side):
     kk, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     bn = dy_ref.shape[-1]
-
-    @pl.when(jnp.logical_and(kk == 0, jnp.logical_and(i == 0, j == 0)))
-    def _init_scalars():
-        dsa_ref[...] = jnp.zeros_like(dsa_ref)
-        dba_ref[...] = jnp.zeros_like(dba_ref)
 
     @pl.when(j == 0)
     def _init_dx():
@@ -510,8 +517,8 @@ def _qmm_bwd_kernel(dy_ref, x_ref, w_ref, as_ref, ab_ref, ws_ref,
         mf = jnp.logical_and(u_x >= -float(q_n_a),
                              u_x <= float(q_p_a)).astype(jnp.float32)
         dx_ref[...] = (dxd * mf).astype(dx_ref.dtype)
-        dsa_ref[0, 0] += jnp.sum(dxd * (xq - mf * u_x))
-        dba_ref[0, 0] += jnp.sum(dxd * (1.0 - mf))
+        dsa_ref[0] = jnp.sum(dxd * (xq - mf * u_x), axis=0, keepdims=True)
+        dba_ref[0] = jnp.sum(dxd * (1.0 - mf), axis=0, keepdims=True)
 
     @pl.when(i == n_i - 1)
     def _fin_dw():
@@ -574,7 +581,7 @@ def bwd_uses_combined(m, k, n, tiles=DEFAULT_TILES, scratch_budget=None):
 def quant_matmul_bwd(dy, x, w, a_scale, a_offset, w_scale, *,
                      q_n_a: int, q_p_a: int, q_n_w: int, q_p_w: int,
                      round_cot: bool = True,
-                     tiles=DEFAULT_TILES, interpret: bool = True,
+                     tiles=DEFAULT_TILES, interpret=None,
                      scratch_budget: int | None = None):
     """Combined backward of quant_matmul — one pallas_call, one HBM read of
     dY/X/W each: (dX, d a_scale_raw, d a_offset_raw, dW, d w_scale_raw).
@@ -631,15 +638,15 @@ def quant_matmul_bwd(dy, x, w, a_scale, a_offset, w_scale, *,
         ],
         out_specs=[
             pl.BlockSpec((bm, bk), lambda kk, i, j: (i, kk)),
-            pl.BlockSpec((1, 1), lambda kk, i, j: (0, 0)),
-            pl.BlockSpec((1, 1), lambda kk, i, j: (0, 0)),
+            pl.BlockSpec((1, 1, bk), lambda kk, i, j: (i, 0, kk)),
+            pl.BlockSpec((1, 1, bk), lambda kk, i, j: (i, 0, kk)),
             pl.BlockSpec((bk, bn), lambda kk, i, j: (kk, j)),
             dws_spec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, k), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((grid[1], 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((grid[1], 1, k), jnp.float32),
             jax.ShapeDtypeStruct((k, n), jnp.float32),
             jax.ShapeDtypeStruct(dws_shape, jnp.float32),
         ],
@@ -647,9 +654,9 @@ def quant_matmul_bwd(dy, x, w, a_scale, a_offset, w_scale, *,
                         pltpu.VMEM((bk, n_pad), jnp.float32),
                         pltpu.VMEM((1, 1) if k_side else (1, n_pad),
                                    jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(dy, x, w, a_s, a_b, w_scale.astype(jnp.float32))
-    return dx, dsa.reshape(()), dba.reshape(()), dw, dws
+    return dx, jnp.sum(dsa), jnp.sum(dba), dw, dws
 
 
 def _qmm_bwd_batched_kernel(dy_ref, x_ref, w_ref, as_ref, ab_ref, ws_ref,
@@ -660,24 +667,19 @@ def _qmm_bwd_batched_kernel(dy_ref, x_ref, w_ref, as_ref, ab_ref, ws_ref,
     kk, i, j = pl.program_id(1), pl.program_id(2), pl.program_id(3)
     bn = dy_ref.shape[-1]
 
-    @pl.when(jnp.logical_and(kk == 0, jnp.logical_and(i == 0, j == 0)))
-    def _init_scalars():
-        dsa_ref[...] = jnp.zeros_like(dsa_ref)
-        dba_ref[...] = jnp.zeros_like(dba_ref)
-
     @pl.when(j == 0)
     def _init_dx():
         dx_acc[...] = jnp.zeros_like(dx_acc)
 
     x = x_ref[0].astype(jnp.float32)
-    a_s = jnp.maximum(as_ref[0, 0], 1e-9)
-    a_b = ab_ref[0, 0]
+    a_s = jnp.maximum(as_ref[0, 0, 0], 1e-9)
+    a_b = ab_ref[0, 0, 0]
     u_x = (x - a_b) / a_s
     xq = jnp.clip(jnp.round(u_x), -float(q_n_a), float(q_p_a))
     xd = (xq * a_s + a_b).astype(jnp.bfloat16)
 
     w = w_ref[0].astype(jnp.float32)
-    w_s = jnp.maximum(ws_ref[...].astype(jnp.float32), 1e-9)  # (1, bn)
+    w_s = jnp.maximum(ws_ref[0].astype(jnp.float32), 1e-9)  # (1, bn)
     u_w = w / w_s
     qw = jnp.clip(jnp.round(u_w), -float(q_n_w), float(q_p_w))
     wd = (qw * w_s).astype(jnp.bfloat16)
@@ -709,8 +711,8 @@ def _qmm_bwd_batched_kernel(dy_ref, x_ref, w_ref, as_ref, ab_ref, ws_ref,
         mf = jnp.logical_and(u_x >= -float(q_n_a),
                              u_x <= float(q_p_a)).astype(jnp.float32)
         dx_ref[0] = (dxd * mf).astype(dx_ref.dtype)
-        dsa_ref[0, 0] += jnp.sum(dxd * (xq - mf * u_x))
-        dba_ref[0, 0] += jnp.sum(dxd * (1.0 - mf))
+        dsa_ref[0, 0] = jnp.sum(dxd * (xq - mf * u_x), axis=0, keepdims=True)
+        dba_ref[0, 0] = jnp.sum(dxd * (1.0 - mf), axis=0, keepdims=True)
 
     @pl.when(i == n_i - 1)
     def _fin_dw():
@@ -734,7 +736,7 @@ def _qmm_bwd_batched_kernel(dy_ref, x_ref, w_ref, as_ref, ab_ref, ws_ref,
 
         @pl.when(kk == n_k - 1)
         def _emit():
-            dws_ref[...] = dws_acc[:, jsl]
+            dws_ref[0] = dws_acc[:, jsl]
 
 
 @functools.partial(jax.jit, static_argnames=("q_n_a", "q_p_a", "q_n_w", "q_p_w",
@@ -743,7 +745,7 @@ def _qmm_bwd_batched_kernel(dy_ref, x_ref, w_ref, as_ref, ab_ref, ws_ref,
 def quant_matmul_bwd_batched(dy, x, w, a_scale, a_offset, w_scale, *,
                              q_n_a: int, q_p_a: int, q_n_w: int, q_p_w: int,
                              round_cot: bool = True,
-                             tiles=DEFAULT_TILES, interpret: bool = True,
+                             tiles=DEFAULT_TILES, interpret=None,
                              scratch_budget: int | None = None):
     """Per-expert combined backward of quant_matmul_batched.
 
@@ -787,37 +789,37 @@ def quant_matmul_bwd_batched(dy, x, w, a_scale, a_offset, w_scale, *,
             pl.BlockSpec((1, bm, bn), lambda ee, kk, i, j: (ee, i, j)),
             pl.BlockSpec((1, bm, bk), lambda ee, kk, i, j: (ee, i, kk)),
             pl.BlockSpec((1, bk, bn), lambda ee, kk, i, j: (ee, kk, j)),
-            pl.BlockSpec((1, 1), lambda ee, kk, i, j: (ee, 0)),
-            pl.BlockSpec((1, 1), lambda ee, kk, i, j: (ee, 0)),
-            pl.BlockSpec((1, bn), lambda ee, kk, i, j: (ee, j)),
+            pl.BlockSpec((1, 1, 1), lambda ee, kk, i, j: (ee, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda ee, kk, i, j: (ee, 0, 0)),
+            pl.BlockSpec((1, 1, bn), lambda ee, kk, i, j: (ee, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((1, bm, bk), lambda ee, kk, i, j: (ee, i, kk)),
-            pl.BlockSpec((1, 1), lambda ee, kk, i, j: (ee, 0)),
-            pl.BlockSpec((1, 1), lambda ee, kk, i, j: (ee, 0)),
+            pl.BlockSpec((1, 1, 1, bk), lambda ee, kk, i, j: (ee, i, 0, kk)),
+            pl.BlockSpec((1, 1, 1, bk), lambda ee, kk, i, j: (ee, i, 0, kk)),
             pl.BlockSpec((1, bk, bn), lambda ee, kk, i, j: (ee, kk, j)),
-            pl.BlockSpec((1, bn), lambda ee, kk, i, j: (ee, j)),
+            pl.BlockSpec((1, 1, bn), lambda ee, kk, i, j: (ee, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((e, m, k), jnp.float32),
-            jax.ShapeDtypeStruct((e, 1), jnp.float32),
-            jax.ShapeDtypeStruct((e, 1), jnp.float32),
+            jax.ShapeDtypeStruct((e, grid[2], 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((e, grid[2], 1, k), jnp.float32),
             jax.ShapeDtypeStruct((e, k, n), jnp.float32),
-            jax.ShapeDtypeStruct((e, n), jnp.float32),
+            jax.ShapeDtypeStruct((e, 1, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32),
                         pltpu.VMEM((bk, n_pad), jnp.float32),
                         pltpu.VMEM((1, n_pad), jnp.float32)],
-        interpret=interpret,
-    )(dy, x, w, a_scale.astype(jnp.float32), a_offset.astype(jnp.float32),
-      w_scale.astype(jnp.float32))
-    return dx, dsa, dba, dw, dws
+        interpret=resolve_interpret(interpret),
+    )(dy, x, w, *_expert_operands(a_scale, a_offset, w_scale))
+    return (dx, jnp.sum(dsa, axis=(1, 2, 3))[:, None],
+            jnp.sum(dba, axis=(1, 2, 3))[:, None], dw, dws[:, 0])
 
 
 @functools.partial(jax.jit, static_argnames=("q_n_w", "q_p_w", "tiles",
                                              "interpret", "out_dtype"))
 def int_matmul(x, w_codes, w_col_scale, *, q_n_w: int, q_p_w: int,
-               tiles=DEFAULT_TILES, interpret: bool = True,
+               tiles=DEFAULT_TILES, interpret=None,
                out_dtype=jnp.float32):
     """Serving variant: W already int8 codes; dequantize tile-wise in VMEM.
 
@@ -855,13 +857,13 @@ def int_matmul(x, w_codes, w_col_scale, *, q_n_w: int, q_p_w: int,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w_codes, w_col_scale.astype(jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("tiles", "interpret", "out_dtype"))
 def int4_matmul(x, w_packed, w_col_scale, *, tiles=DEFAULT_TILES,
-                interpret: bool = True, out_dtype=jnp.float32):
+                interpret=None, out_dtype=jnp.float32):
     """Serving matmul over NIBBLE-PACKED int4 weight codes.
 
     w_packed: (K//2, N) int8, byte p holding code row 2p in the low nibble and
@@ -909,5 +911,5 @@ def int4_matmul(x, w_packed, w_col_scale, *, tiles=DEFAULT_TILES,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w_packed, w_col_scale.astype(jnp.float32))

@@ -16,6 +16,9 @@ in-flight work (the `executor_factory` closure below), and SIGTERM/SIGINT
 (PreemptionGuard) triggers a graceful drain bounded by `--drain-timeout` —
 in-flight requests finish or are cut with partial results, never lost.
 
+`run_serving` is that wiring as a function: the CLI and `chip_smoke.py`
+both call it.
+
 `greedy_generate` is the engine-free batched loop: ONE chunked-prefill step
 over the whole prompt, then new_tokens - 1 single-token decode steps — the
 serving engine's per-request outputs match it exactly (the parity contract
@@ -33,7 +36,8 @@ from repro.configs.registry import ARCH_IDS, get_config, reduced_config
 from repro.core.policy import get_preset
 from repro.data.synthetic import DataConfig, sample_batch
 from repro.dist import sharding as shard
-from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import setup_compile_cache
+from repro.launch.mesh import kernels_for_mesh, make_host_mesh
 from repro.models import model as M
 from repro.models.common import convert_to_serving
 from repro.serve import (FaultPolicy, ModelExecutor, SamplingParams,
@@ -72,6 +76,47 @@ def greedy_generate(step, params, cache, prompts, new_tokens: int):
     return jnp.concatenate(outs, 1), cache
 
 
+def run_serving(cfg, qcfg, prompts, *, new_tokens: int, n_slots: int,
+                max_len: int, chunk: int, model_parallel: int = 1,
+                drain_timeout: float = 30.0, devices=None):
+    """Serve `prompts` (a list of int token arrays) through `ServeEngine`
+    over `ModelExecutor`: random serving weights from seed 0, sharded over a
+    (data, model) mesh of `devices` (default: all), one pooled KV cache of
+    `n_slots` x `max_len`. Every request asks for `new_tokens` greedy
+    tokens. Returns (engine, mesh, summary); `engine.results` holds each
+    request's GenResult under rid "req-<i>"."""
+    mesh = make_host_mesh(model=model_parallel, devices=devices)
+    qcfg = kernels_for_mesh(qcfg, mesh)
+    key = jax.random.PRNGKey(0)
+    params = convert_to_serving(M.init_params(key, cfg, qcfg), qcfg)
+    p_sh = shard.named_tree(shard.param_pspecs(params, mesh), mesh)
+    params = jax.device_put(params, p_sh)
+
+    # the pool's slot axis stays unsharded (per-slot dynamic-slice inserts);
+    # the KV sequence axis still shards over the model axis
+    def shard_caches(cache):
+        specs = shard.cache_pspecs(cache, mesh, shard_batch=False)
+        return jax.device_put(cache, shard.named_tree(specs, mesh))
+
+    def make_executor():
+        # sentinel rebuild path: params/cfg stay valid, only the executor
+        # (jit closures + caches) is rebuilt; in-flight work is replayed
+        return ModelExecutor(params, cfg, qcfg, n_slots=n_slots,
+                             max_len=max_len, chunk=chunk,
+                             shard_caches=shard_caches)
+
+    engine = ServeEngine(
+        make_executor(), Scheduler(max_len=max_len, max_queue=len(prompts)),
+        executor_factory=make_executor, guard=PreemptionGuard(),
+        faults=FaultPolicy(drain_timeout_s=drain_timeout))
+    for i, prompt in enumerate(prompts):
+        ok, reason = engine.submit(prompt,
+                                   SamplingParams(max_new_tokens=new_tokens),
+                                   rid=f"req-{i}")
+        assert ok, reason
+    return engine, mesh, engine.run_until_idle()
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-8b", choices=ARCH_IDS)
@@ -91,46 +136,21 @@ def main():
                     dest="drain_timeout",
                     help="graceful-drain budget (s) on SIGTERM/preemption")
     args = ap.parse_args()
+    setup_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduced_config(cfg)
     qcfg = get_preset(args.quant).replace(kv_cache_bits=args.kv_bits,
                                           a_bits=32)
-    mesh = make_host_mesh(model=args.mp)
-    key = jax.random.PRNGKey(0)
-    params = convert_to_serving(M.init_params(key, cfg, qcfg), qcfg)
-    p_sh = shard.named_tree(shard.param_pspecs(params, mesh), mesh)
-    params = jax.device_put(params, p_sh)
-
-    # the pool's slot axis stays unsharded (per-slot dynamic-slice inserts);
-    # the KV sequence axis still shards over the model axis
-    def shard_caches(cache):
-        specs = shard.cache_pspecs(cache, mesh, shard_batch=False)
-        return jax.device_put(cache, shard.named_tree(specs, mesh))
-
-    max_len = args.prompt_len + args.new_tokens
     n_slots = args.slots or min(args.batch, 4)
-
-    def make_executor():
-        # sentinel rebuild path: params/cfg stay valid, only the executor
-        # (jit closures + caches) is rebuilt; in-flight work is replayed
-        return ModelExecutor(params, cfg, qcfg, n_slots=n_slots,
-                             max_len=max_len, chunk=args.chunk,
-                             shard_caches=shard_caches)
-
-    engine = ServeEngine(
-        make_executor(), Scheduler(max_len=max_len, max_queue=args.batch),
-        executor_factory=make_executor, guard=PreemptionGuard(),
-        faults=FaultPolicy(drain_timeout_s=args.drain_timeout))
     prompts = np.asarray(sample_batch(cfg, DataConfig(), 0, args.batch,
                                       args.prompt_len)["tokens"])
-    for i in range(args.batch):
-        ok, reason = engine.submit(prompts[i],
-                                   SamplingParams(max_new_tokens=args.new_tokens),
-                                   rid=f"req-{i}")
-        assert ok, reason
-    summary = engine.run_until_idle()
+    engine, mesh, summary = run_serving(
+        cfg, qcfg, list(prompts), new_tokens=args.new_tokens,
+        n_slots=n_slots, max_len=args.prompt_len + args.new_tokens,
+        chunk=args.chunk, model_parallel=args.mp,
+        drain_timeout=args.drain_timeout)
 
     tp = summary["throughput"]
     print(f"arch={cfg.name} mesh={dict(mesh.shape)} int{args.kv_bits}-KV "

@@ -13,14 +13,15 @@ an LR backoff (bounded retries, then SentinelAbort). `--no-sentinel`
 disables all of it so benchmarks can measure the sentinel's overhead.
 
 The loop itself lives in `run_training()` so the fault-injection suite
-(tests/test_sentinel_faults.py) can drive it in-process with deterministic
-injectors (repro/testing/faultinject.py). On a real TPU slice the same
-entrypoint runs unmodified (jax.distributed.initialize is attempted when
-the JAX_COORDINATOR_ADDRESS env var is present); on this CPU container use
---smoke for reduced configs.
+(tests/test_sentinel_faults.py) and `chip_smoke.py` can drive it in-process
+(the suite with deterministic injectors, repro/testing/faultinject.py). On a
+TPU slice the same entrypoint runs unmodified (jax.distributed.initialize is
+attempted when the JAX_COORDINATOR_ADDRESS env var is present); on a CPU
+use --smoke for reduced configs.
 
-XLA flags for real runs (latency-hiding collective overlap) are appended via
-LIBTPU_INIT_ARGS / XLA_FLAGS when --tpu-flags is passed.
+`--tpu-flags` turns on latency-hiding collective overlap. These are libtpu
+flags: they go in LIBTPU_INIT_ARGS, which libtpu parses once when the
+backend starts (in XLA_FLAGS they abort the process as unknown flags).
 """
 from __future__ import annotations
 
@@ -37,7 +38,8 @@ from repro.core.policy import get_preset
 from repro.data.mckd_store import synthetic_kd_labels
 from repro.data.synthetic import DataConfig, sample_batch
 from repro.dist import sharding as shard
-from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import setup_compile_cache
+from repro.launch.mesh import kernels_for_mesh, make_host_mesh
 from repro.optim.adamw import AdamWConfig
 from repro.train import checkpoint as ckpt
 from repro.train.fault_tolerance import CheckpointManager
@@ -63,6 +65,8 @@ class RunReport:
     lr_scale: float           # final sentinel LR backoff multiplier
     preempted: bool           # SIGTERM/SIGINT clean exit taken
     straggler_flags: int
+    losses: list = dataclasses.field(default_factory=list)  # per step run
+    step_fn: Optional[Callable] = None  # the jitted train step
 
 
 def run_training(cfg, qcfg, tcfg: TrainConfig, dcfg: DataConfig, *,
@@ -72,7 +76,7 @@ def run_training(cfg, qcfg, tcfg: TrainConfig, dcfg: DataConfig, *,
                  extra_loss: Optional[Callable] = None,
                  on_step: Optional[Callable] = None,
                  mgr: Optional[CheckpointManager] = None,
-                 seed: int = 0) -> RunReport:
+                 seed: int = 0, devices=None) -> RunReport:
     """The QAT training loop: restore -> step -> health -> save, with
     sentinel rollback recovery. `tcfg.sentinel` (SentinelConfig | None)
     controls the health checks; None runs the bare loop.
@@ -84,8 +88,10 @@ def run_training(cfg, qcfg, tcfg: TrainConfig, dcfg: DataConfig, *,
     mgr: pass a preconfigured CheckpointManager (tests use async_io=False
         for determinism); by default one is built over `ckpt_dir` with a
         (arch, quant) config fingerprint stamped into every manifest.
+    devices: the devices the (data, model) mesh spans (default: all).
     """
-    mesh = make_host_mesh(model=model_parallel)
+    mesh = make_host_mesh(model=model_parallel, devices=devices)
+    run_qcfg = kernels_for_mesh(qcfg, mesh)
     key = jax.random.PRNGKey(seed)
     constrain, logits_constrain = shard.make_constrains(mesh)
     like = jax.eval_shape(lambda k: init_state(k, cfg, qcfg, tcfg), key)
@@ -100,9 +106,10 @@ def run_training(cfg, qcfg, tcfg: TrainConfig, dcfg: DataConfig, *,
         like, shardings=state_sh)
     if start:
         print(f"restored from step {start} (elastic reshard onto "
-              f"{len(jax.devices())} devices)")
+              f"{mesh.size} devices)")
 
-    step_fn = jax.jit(make_train_step(cfg, qcfg, tcfg, constrain=constrain,
+    step_fn = jax.jit(make_train_step(cfg, run_qcfg, tcfg,
+                                      constrain=constrain,
                                       logits_constrain=logits_constrain,
                                       extra_loss=extra_loss),
                       in_shardings=(state_sh, None),
@@ -113,6 +120,7 @@ def run_training(cfg, qcfg, tcfg: TrainConfig, dcfg: DataConfig, *,
     host = jax.process_index()
     t0 = time.monotonic()
     m: dict = {}
+    losses: list = []
     steps_run = 0
     preempted = False
     # A checkpoint labelled s is taken AFTER loop index s completed, so a
@@ -130,6 +138,7 @@ def run_training(cfg, qcfg, tcfg: TrainConfig, dcfg: DataConfig, *,
                                          tcfg.kd_topk, seed=i)
             batch.update(kd_idx=idx, kd_p=p)
         state, m = step_fn(state, batch)
+        losses.append(m["loss"])  # device scalar: no host sync here
         steps_run += 1
         slow = mgr.straggler.tick()
         if runner is not None:
@@ -167,7 +176,9 @@ def run_training(cfg, qcfg, tcfg: TrainConfig, dcfg: DataConfig, *,
         skipped=int(m.get("sentinel_skipped", 0)) if m else 0,
         lr_scale=float(m.get("lr_scale", 1.0)) if m else 1.0,
         preempted=preempted,
-        straggler_flags=mgr.straggler.flags)
+        straggler_flags=mgr.straggler.flags,
+        losses=[float(v) for v in losses],
+        step_fn=step_fn)
 
 
 def main():
@@ -193,11 +204,12 @@ def main():
                          "(overhead benchmarking escape hatch)")
     args = ap.parse_args()
 
-    if args.tpu_flags:
-        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " "
-                                   + TPU_PERF_FLAGS)
+    if args.tpu_flags:  # before anything starts the backend
+        os.environ["LIBTPU_INIT_ARGS"] = (
+            os.environ.get("LIBTPU_INIT_ARGS", "") + " " + TPU_PERF_FLAGS)
     if "JAX_COORDINATOR_ADDRESS" in os.environ:  # multi-host slice
         jax.distributed.initialize()
+    setup_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

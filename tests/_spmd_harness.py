@@ -5,12 +5,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.registry import get_config, reduced_config
 from repro.core.policy import QuantConfig
 from repro.data.synthetic import DataConfig, sample_batch
 from repro.dist import sharding as shard
+from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 from repro.optim.adamw import AdamWConfig
 from repro.optim.grad_compress import compressed_psum
@@ -41,7 +41,7 @@ def run_training(mesh, cfg, qcfg, tcfg, key, dcfg, n_steps=8):
 
 def main():
     assert len(jax.devices()) == 8
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh(model=4)
     cfg = reduced_config(get_config("granite-8b")).replace(
         n_layers=2, d_model=64, n_heads=4, n_kv_heads=4, head_dim=16, d_ff=96)
     qcfg = QuantConfig(w_bits=4, a_bits=4, mode="mdq")
@@ -61,7 +61,7 @@ def main():
     def comp(v):
         return compressed_psum(v, "data")
 
-    got = shard_map(comp, mesh=mesh, in_specs=P("data", None),
+    got = jax.shard_map(comp, mesh=mesh, in_specs=P("data", None),
                     out_specs=P(None, None))(xs)
     rel = float(jnp.linalg.norm(got[0] - exact[0]) / jnp.linalg.norm(exact[0]))
 

@@ -10,7 +10,7 @@ from repro.dist import sharding as shard
 from repro.models import model as M
 from repro.train.state import TrainConfig, init_state
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
 QCFG = QuantConfig(w_bits=4, a_bits=4, mode="mdq")
 
 
