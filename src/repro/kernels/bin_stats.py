@@ -66,5 +66,6 @@ def bin_stats_2d(w, scale, *, q_n: int, q_p: int, block=DEFAULT_BLOCK,
         out_specs=pl.BlockSpec((3, n_bins), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((3, n_bins), jnp.float32),
         scratch_shapes=[pltpu.VMEM((3, n_bins), jnp.float32)],
+        name="bin_stats_2d",
         interpret=resolve_interpret(interpret),
     )(w, s2)

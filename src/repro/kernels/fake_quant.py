@@ -67,6 +67,7 @@ def fake_quant_2d(x, scale, offset=None, *, q_n: int, q_p: int,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        name="fake_quant_2d",
         interpret=resolve_interpret(interpret),
     )(x, s2, b2)
 
@@ -89,5 +90,6 @@ def fake_quant_rows(x, row_scale, *, q_n: int, q_p: int,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        name="fake_quant_rows",
         interpret=resolve_interpret(interpret),
     )(x, row_scale.astype(jnp.float32))

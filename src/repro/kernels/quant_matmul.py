@@ -109,6 +109,7 @@ def quant_matmul(x, w, a_scale, a_offset, w_scale, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="quant_matmul",
         interpret=resolve_interpret(interpret),
     )(x, w, a_s, a_b, w_scale.astype(jnp.float32))
 
@@ -181,6 +182,7 @@ def quant_matmul_batched(x, w, a_scale, a_offset, w_scale, *,
         out_specs=pl.BlockSpec((1, bm, bn), lambda ee, i, j, kk: (ee, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="quant_matmul_batched",
         interpret=resolve_interpret(interpret),
     )(x, w, *_expert_operands(a_scale, a_offset, w_scale))
 
@@ -299,6 +301,7 @@ def quant_matmul_dx(dy, x, w, a_scale, a_offset, w_scale, *,
             jax.ShapeDtypeStruct((grid[0], 1, k), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
+        name="quant_matmul_dx",
         interpret=resolve_interpret(interpret),
     )(dy, w, w_scale.astype(jnp.float32), x, a_s, a_b)
     return dx, jnp.sum(dsa), jnp.sum(dba)
@@ -423,6 +426,7 @@ def quant_matmul_dw(dy, x, w, a_scale, a_offset, w_scale, *,
             jax.ShapeDtypeStruct(dws_shape, jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32), dws_scratch],
+        name="quant_matmul_dw",
         interpret=resolve_interpret(interpret),
     )(x, dy, a_s, a_b, w, w_scale.astype(jnp.float32))
     return dw, dws
@@ -654,6 +658,7 @@ def quant_matmul_bwd(dy, x, w, a_scale, a_offset, w_scale, *,
                         pltpu.VMEM((bk, n_pad), jnp.float32),
                         pltpu.VMEM((1, 1) if k_side else (1, n_pad),
                                    jnp.float32)],
+        name="quant_matmul_bwd",
         interpret=resolve_interpret(interpret),
     )(dy, x, w, a_s, a_b, w_scale.astype(jnp.float32))
     return dx, jnp.sum(dsa), jnp.sum(dba), dw, dws
@@ -810,6 +815,7 @@ def quant_matmul_bwd_batched(dy, x, w, a_scale, a_offset, w_scale, *,
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32),
                         pltpu.VMEM((bk, n_pad), jnp.float32),
                         pltpu.VMEM((1, n_pad), jnp.float32)],
+        name="quant_matmul_bwd_batched",
         interpret=resolve_interpret(interpret),
     )(dy, x, w, *_expert_operands(a_scale, a_offset, w_scale))
     return (dx, jnp.sum(dsa, axis=(1, 2, 3))[:, None],
@@ -857,6 +863,7 @@ def int_matmul(x, w_codes, w_col_scale, *, q_n_w: int, q_p_w: int,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="int_matmul",
         interpret=resolve_interpret(interpret),
     )(x, w_codes, w_col_scale.astype(jnp.float32))
 
@@ -911,5 +918,6 @@ def int4_matmul(x, w_packed, w_col_scale, *, tiles=DEFAULT_TILES,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="int4_matmul",
         interpret=resolve_interpret(interpret),
     )(x, w_packed, w_col_scale.astype(jnp.float32))

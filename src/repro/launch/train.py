@@ -33,6 +33,7 @@ from typing import Callable, Optional
 
 import jax
 
+from repro import tracing
 from repro.configs.registry import ARCH_IDS, get_config, reduced_config
 from repro.core.policy import get_preset
 from repro.data.mckd_store import synthetic_kd_labels
@@ -118,7 +119,7 @@ def run_training(cfg, qcfg, tcfg: TrainConfig, dcfg: DataConfig, *,
               if tcfg.sentinel is not None else None)
 
     host = jax.process_index()
-    t0 = time.monotonic()
+    t_log, n_log = time.monotonic(), 0   # host clock and steps since last log
     m: dict = {}
     losses: list = []
     steps_run = 0
@@ -128,44 +129,60 @@ def run_training(cfg, qcfg, tcfg: TrainConfig, dcfg: DataConfig, *,
     # (step, host)-keyed, so the replay is identical).
     i = start if start == 0 else start + 1
     while i < steps:
-        if on_step is not None:
-            injected = on_step(i, state)
-            if injected is not None:
-                state = injected
-        batch = sample_batch(cfg, dcfg, i, batch_size, seq_len, host_index=host)
-        if tcfg.kd == "mckd":
-            idx, p = synthetic_kd_labels(batch["labels"], cfg.vocab_size,
-                                         tcfg.kd_topk, seed=i)
-            batch.update(kd_idx=idx, kd_p=p)
-        state, m = step_fn(state, batch)
-        losses.append(m["loss"])  # device scalar: no host sync here
-        steps_run += 1
-        slow = mgr.straggler.tick()
-        if runner is not None:
-            health = int(m["health"])
-            if health:
-                print(f"step {i:5d} health={describe(health)} "
-                      f"(skipped={int(m['sentinel_skipped'])})", flush=True)
-            if runner.observe(health):
-                state, i = runner.rollback(state)
-                print(f"sentinel: {runner.scfg.k_consecutive} consecutive "
-                      f"fatal steps -> rolled back to step {i - 1}, "
-                      f"lr_scale={float(state['sent'].lr_scale):.3g} "
-                      f"(retry {runner.retries}/{runner.scfg.max_retries})",
-                      flush=True)
-                continue
-        if log_every and i % log_every == 0:
-            dt = (time.monotonic() - t0) / max(steps_run, 1)
-            print(f"step {i:5d} loss={float(m['loss']):.4f} "
-                  f"lr={float(m['lr']):.2e} {dt:.2f}s/step"
-                  f"{' STRAGGLER' if slow else ''}", flush=True)
-        mgr.maybe_save(state, i)
-        if mgr.should_stop():
-            print("preemption: final forced checkpoint + clean exit")
-            mgr.maybe_save(state, i, force=True)
-            preempted = True
-            break
-        i += 1
+        with jax.profiler.StepTraceAnnotation(tracing.TRAIN_STEP, step_num=i):
+            if on_step is not None:
+                with tracing.span(tracing.TRAIN_HOOK):
+                    injected = on_step(i, state)
+                if injected is not None:
+                    state = injected
+            with tracing.span(tracing.TRAIN_INPUT):
+                batch = sample_batch(cfg, dcfg, i, batch_size, seq_len,
+                                     host_index=host)
+                if tcfg.kd == "mckd":
+                    idx, p = synthetic_kd_labels(batch["labels"],
+                                                 cfg.vocab_size,
+                                                 tcfg.kd_topk, seed=i)
+                    batch.update(kd_idx=idx, kd_p=p)
+            with tracing.span(tracing.TRAIN_DISPATCH):
+                state, m = step_fn(state, batch)
+            losses.append(m["loss"])  # device scalar: no host sync here
+            steps_run += 1
+            n_log += 1
+            if runner is not None:
+                with tracing.span(tracing.TRAIN_SYNC):
+                    health = int(m["health"])
+                if health:
+                    with tracing.span(tracing.TRAIN_SYNC):
+                        skipped = int(m["sentinel_skipped"])
+                    print(f"step {i:5d} health={describe(health)} "
+                          f"(skipped={skipped})", flush=True)
+                if runner.observe(health):
+                    state, i = runner.rollback(state)
+                    print(f"sentinel: {runner.scfg.k_consecutive} "
+                          f"consecutive fatal steps -> rolled back to step "
+                          f"{i - 1}, lr_scale="
+                          f"{float(state['sent'].lr_scale):.3g} (retry "
+                          f"{runner.retries}/{runner.scfg.max_retries})",
+                          flush=True)
+                    continue
+            with tracing.span(tracing.TRAIN_SAVE):
+                slow = mgr.straggler.tick()
+                mgr.maybe_save(state, i)
+                stop = mgr.should_stop()
+            if log_every and i % log_every == 0:
+                with tracing.span(tracing.TRAIN_SYNC):
+                    loss, lr = map(float, jax.device_get((m["loss"], m["lr"])))
+                now = time.monotonic()
+                print(f"step {i:5d} loss={loss:.4f} lr={lr:.2e} "
+                      f"{(now - t_log) / n_log:.2f}s/step"
+                      f"{' STRAGGLER' if slow else ''}", flush=True)
+                t_log, n_log = now, 0
+            if stop:
+                print("preemption: final forced checkpoint + clean exit")
+                mgr.maybe_save(state, i, force=True)
+                preempted = True
+                break
+            i += 1
     mgr.finalize()
     mgr.guard.restore_handlers()
     return RunReport(

@@ -23,6 +23,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.configs.base import ArchConfig, BlockDef
 from repro.core.policy import QuantConfig, weight_spec
 from repro.models import attention as attn
@@ -323,10 +324,11 @@ def forward(params: dict, batch: dict, cfg: ArchConfig, qcfg: QuantConfig, *,
         aux_sum = jax.tree.map(lambda t, v: t + v, aux_sum, a)
 
     x = apply_norm(params["final_norm"], x, cfg.norm)
-    logits = lm_head_apply(
-        params["lm_head"], x, qcfg, cfg.vocab_size, cfg.padded_vocab,
-        final_softcap=cfg.final_softcap,
-        tied_embed=params["embed"] if cfg.tie_embeddings else None)
+    with jax.named_scope(tracing.LM_HEAD):
+        logits = lm_head_apply(
+            params["lm_head"], x, qcfg, cfg.vocab_size, cfg.padded_vocab,
+            final_softcap=cfg.final_softcap,
+            tied_embed=params["embed"] if cfg.tie_embeddings else None)
     logits = logits_constrain(logits)
     aux_sum["act_sdam"] = aux_sum.pop("sdam_sum") / max(cfg.n_layers, 1)
     if collect_cache:
@@ -462,7 +464,9 @@ def prefill_step(params: dict, cache: dict, batch: dict, cfg: ArchConfig,
                                      gc[i], pos, fe, cdtype, constrain)
                 ncs.append(nc)
             return x, tuple(ncs)
-        x, gcache = jax.lax.scan(group_fn, x, (params["groups"], cache["groups"]))
+        with jax.named_scope(tracing.LAYER_SCAN):
+            x, gcache = jax.lax.scan(group_fn, x,
+                                     (params["groups"], cache["groups"]))
         new_cache["groups"] = gcache
     for i in range(cfg.n_tail):
         x, nc = block_decode(params["tail"][i], x, cfg.pattern[i], cfg, qcfg,
@@ -470,10 +474,11 @@ def prefill_step(params: dict, cache: dict, batch: dict, cfg: ArchConfig,
         new_cache["tail"] = new_cache["tail"] + (nc,)
 
     x = apply_norm(params["final_norm"], x, cfg.norm)
-    logits = lm_head_apply(
-        params["lm_head"], x, qcfg, cfg.vocab_size, cfg.padded_vocab,
-        final_softcap=cfg.final_softcap,
-        tied_embed=params["embed"] if cfg.tie_embeddings else None)
+    with jax.named_scope(tracing.LM_HEAD):
+        logits = lm_head_apply(
+            params["lm_head"], x, qcfg, cfg.vocab_size, cfg.padded_vocab,
+            final_softcap=cfg.final_softcap,
+            tied_embed=params["embed"] if cfg.tie_embeddings else None)
     return logits_constrain(logits), new_cache
 
 

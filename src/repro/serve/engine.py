@@ -62,6 +62,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from repro import tracing
 from repro.serve.metrics import MetricsCollector
 from repro.serve.sampling import SamplingParams, is_finished, sample_token
 from repro.serve.scheduler import Request, Scheduler
@@ -187,7 +188,8 @@ class ModelExecutor:
         ps[0, :n] = np.arange(start_pos, start_pos + n)
         logits, self.scratch = self._prefill(self.params, self.scratch,
                                              jnp.asarray(tk), jnp.asarray(ps))
-        return np.asarray(logits[0, n - 1], np.float32)
+        with tracing.span(tracing.SERVE_SYNC):
+            return np.asarray(logits[0, n - 1], np.float32)
 
     def commit_prefill(self, slot: int) -> None:
         self.pool = self._insert(self.pool, self.scratch, slot)
@@ -200,7 +202,8 @@ class ModelExecutor:
         logits, self.pool = self._decode(self.params, self.pool,
                                          jnp.asarray(tokens[:, None]),
                                          jnp.asarray(pos))
-        return np.asarray(logits[:, 0], np.float32)
+        with tracing.span(tracing.SERVE_SYNC):
+            return np.asarray(logits[:, 0], np.float32)
 
     def reset_slot(self, slot: int) -> None:
         self.pool = self._insert(self.pool, self.template, slot)
@@ -407,34 +410,41 @@ class ServeEngine:
 
     # -- one engine iteration ------------------------------------------------
     def step(self) -> bool:
+        with tracing.span(tracing.SERVE_STEP, queue=len(self.scheduler),
+                          active=len(self._generating),
+                          pending=len(self._pending_prefill)):
+            return self._step()
+
+    def _step(self) -> bool:
         now = self.clock()
-        for req, reason in self.scheduler.expire(now):
-            if reason == "expired":
-                self.metrics.on_expire(req.rid, now)
-            else:  # deadline passed while queued: admission-side shedding
-                self.metrics.on_shed(req.rid, reason, now)
         did = False
+        with tracing.span(tracing.SERVE_SCHEDULE):
+            for req, reason in self.scheduler.expire(now):
+                if reason == "expired":
+                    self.metrics.on_expire(req.rid, now)
+                else:  # deadline passed while queued: admission shedding
+                    self.metrics.on_shed(req.rid, reason, now)
 
-        # in-flight deadlines: cut the request, keep its partial tokens
-        for slot in sorted(self.slots):
-            dl = self.slots[slot].req.deadline
-            if dl is not None and now > dl:
-                self._finish(slot, "deadline", now)
-                did = True
+            # in-flight deadlines: cut the request, keep its partial tokens
+            for slot in sorted(self.slots):
+                dl = self.slots[slot].req.deadline
+                if dl is not None and now > dl:
+                    self._finish(slot, "deadline", now)
+                    did = True
 
-        # admission: fill free slots per the scheduler policy (suspended
-        # while draining — drain() already shed the queue, and submit()
-        # rejects new work)
-        if not self._draining:
-            free = sorted(self._free)
-            admits = self.scheduler.admit(now, len(free), len(self.slots))
-            for req in admits:
-                slot = free.pop(0)
-                self._free.discard(slot)
-                self.slots[slot] = _SlotState(req=req)
-                self._pending_prefill.append(slot)
-                self.metrics.on_admit(req.rid, now)
-                did = True
+            # admission: fill free slots per the scheduler policy (suspended
+            # while draining — drain() already shed the queue, and submit()
+            # rejects new work)
+            if not self._draining:
+                free = sorted(self._free)
+                admits = self.scheduler.admit(now, len(free), len(self.slots))
+                for req in admits:
+                    slot = free.pop(0)
+                    self._free.discard(slot)
+                    self.slots[slot] = _SlotState(req=req)
+                    self._pending_prefill.append(slot)
+                    self.metrics.on_admit(req.rid, now)
+                    did = True
 
         # chunked prefill: one chunk of the oldest admitted prompt (batch-1
         # scratch — one request prefills at a time, others wait their turn)
@@ -447,38 +457,43 @@ class ServeEngine:
             st = self.slots[slot]
             prompt = st.req.tokens
             n = min(self.chunk, prompt.shape[0] - st.cursor)
-            t0 = self.clock()
-            out = self._exec("prefill_chunk",
-                             prompt[st.cursor:st.cursor + n], st.cursor)
-            if out is _REBUILT:
-                return True  # replay re-queued the slot at cursor 0
-            st.last_logits = out
-            self.metrics.on_prefill_chunk(n, self.clock() - t0)
-            st.cursor += n
-            did = True
-            if st.cursor >= prompt.shape[0]:
-                if self._exec("commit_prefill", slot) is _REBUILT:
+            with tracing.span(tracing.SERVE_PREFILL, rid=st.req.rid,
+                              start=st.cursor):
+                t0 = self.clock()
+                out = self._exec("prefill_chunk",
+                                 prompt[st.cursor:st.cursor + n], st.cursor)
+                if out is _REBUILT:
+                    return True  # replay re-queued the slot at cursor 0
+                st.last_logits = out
+                self.metrics.on_prefill_chunk(n, self.clock() - t0)
+                st.cursor += n
+                did = True
+                done = st.cursor >= prompt.shape[0]
+                if done and self._exec("commit_prefill", slot) is _REBUILT:
                     return True
+            if done:
                 self._prefilling = None
-                tnow = self.clock()
-                row = st.last_logits
-                if (self.faults.nonfinite_fault
-                        and not np.all(np.isfinite(row))):
-                    # prefill rows come from the scratch cache, not the pool
-                    # slot, so they fault the request without striking the
-                    # slot (quarantine is for pool-row pathologies)
-                    self.metrics.on_nonfinite(st.req.rid, None, tnow)
-                    self._finish(slot, "fault", tnow)
-                else:
-                    tok = sample_token(row, st.req.sampling, 0)
-                    st.out.append(tok)
-                    self.metrics.on_token(st.req.rid, tnow)
-                    reason = is_finished(st.out, st.req.sampling)
-                    if reason:
-                        self._finish(slot, reason, tnow)
+                with tracing.span(tracing.SERVE_SAMPLE):
+                    tnow = self.clock()
+                    row = st.last_logits
+                    if (self.faults.nonfinite_fault
+                            and not np.all(np.isfinite(row))):
+                        # prefill rows come from the scratch cache, not the
+                        # pool slot, so they fault the request without
+                        # striking the slot (quarantine is for pool-row
+                        # pathologies)
+                        self.metrics.on_nonfinite(st.req.rid, None, tnow)
+                        self._finish(slot, "fault", tnow)
                     else:
-                        st.state = GENERATING
-                        self._generating.add(slot)
+                        tok = sample_token(row, st.req.sampling, 0)
+                        st.out.append(tok)
+                        self.metrics.on_token(st.req.rid, tnow)
+                        reason = is_finished(st.out, st.req.sampling)
+                        if reason:
+                            self._finish(slot, reason, tnow)
+                        else:
+                            st.state = GENERATING
+                            self._generating.add(slot)
 
         # pooled decode over every generating slot
         gen = sorted(self._generating)
@@ -490,55 +505,58 @@ class ServeEngine:
                 tokens[s] = st.out[-1]
                 # the token being fed sits at prompt_len + generated - 1
                 pos[s] = st.req.tokens.shape[0] + len(st.out) - 1
-            t0 = self.clock()
-            logits = self._exec("decode", tokens, pos)
-            if logits is _REBUILT:
-                return True  # next step re-issues the identical decode
-            self.metrics.on_decode_step(len(gen), self.n_slots,
-                                        self.clock() - t0)
-            tnow = self.clock()
-            for s in gen:
-                st = self.slots[s]
-                row = logits[s]
-                if (self.faults.nonfinite_fault
-                        and not np.all(np.isfinite(row))):
-                    # fail ONLY this request; strike the slot — repeated
-                    # non-finite rows mean the pool row itself is sick
-                    self.metrics.on_nonfinite(st.req.rid, s, tnow)
-                    self._strikes[s] = self._strikes.get(s, 0) + 1
-                    self._finish(s, "fault", tnow)
-                    if self._strikes[s] >= self.faults.quarantine_after:
-                        self.quarantine(s, reason="nonfinite_rows")
-                    continue
-                self._strikes[s] = 0
-                tok = sample_token(row, st.req.sampling, len(st.out))
-                st.out.append(tok)
-                self.metrics.on_token(st.req.rid, tnow)
-                reason = is_finished(st.out, st.req.sampling)
-                if reason:
-                    self._finish(s, reason, tnow)
+            with tracing.span(tracing.SERVE_DECODE, active=len(gen)):
+                t0 = self.clock()
+                logits = self._exec("decode", tokens, pos)
+                if logits is _REBUILT:
+                    return True  # next step re-issues the identical decode
+                self.metrics.on_decode_step(len(gen), self.n_slots,
+                                            self.clock() - t0)
+            with tracing.span(tracing.SERVE_SAMPLE):
+                tnow = self.clock()
+                for s in gen:
+                    st = self.slots[s]
+                    row = logits[s]
+                    if (self.faults.nonfinite_fault
+                            and not np.all(np.isfinite(row))):
+                        # fail ONLY this request; strike the slot — repeated
+                        # non-finite rows mean the pool row itself is sick
+                        self.metrics.on_nonfinite(st.req.rid, s, tnow)
+                        self._strikes[s] = self._strikes.get(s, 0) + 1
+                        self._finish(s, "fault", tnow)
+                        if self._strikes[s] >= self.faults.quarantine_after:
+                            self.quarantine(s, reason="nonfinite_rows")
+                        continue
+                    self._strikes[s] = 0
+                    tok = sample_token(row, st.req.sampling, len(st.out))
+                    st.out.append(tok)
+                    self.metrics.on_token(st.req.rid, tnow)
+                    reason = is_finished(st.out, st.req.sampling)
+                    if reason:
+                        self._finish(s, reason, tnow)
             did = True
         return did
 
     def _finish(self, slot: int, reason: str, now: float) -> None:
         st = self.slots.pop(slot)
-        # membership cleanup BEFORE the reset call: a rebuild inside
-        # reset_slot replays from these sets, which must not name a slot
-        # that no longer has state
-        self._generating.discard(slot)
-        if self._prefilling == slot:
-            self._prefilling = None
-        try:
-            self._pending_prefill.remove(slot)
-        except ValueError:
-            pass
-        self.metrics.on_finish(st.req.rid, reason, now)
-        self.results[st.req.rid] = GenResult(
-            st.req.rid, int(st.req.tokens.shape[0]), list(st.out), reason)
-        # _REBUILT is fine here: the rebuilt pool's row is already pristine
-        self._exec("reset_slot", slot)
-        if slot not in self.quarantined:
-            self._free.add(slot)
+        with tracing.span(tracing.SERVE_FINISH, rid=st.req.rid):
+            # membership cleanup BEFORE the reset call: a rebuild inside
+            # reset_slot replays from these sets, which must not name a slot
+            # that no longer has state
+            self._generating.discard(slot)
+            if self._prefilling == slot:
+                self._prefilling = None
+            try:
+                self._pending_prefill.remove(slot)
+            except ValueError:
+                pass
+            self.metrics.on_finish(st.req.rid, reason, now)
+            self.results[st.req.rid] = GenResult(
+                st.req.rid, int(st.req.tokens.shape[0]), list(st.out), reason)
+            # _REBUILT is fine here: the rebuilt pool's row is already pristine
+            self._exec("reset_slot", slot)
+            if slot not in self.quarantined:
+                self._free.add(slot)
 
     # -- drain / run loops ---------------------------------------------------
     def drain(self, timeout_s: Optional[float] = None) -> dict:
