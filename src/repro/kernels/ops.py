@@ -91,26 +91,28 @@ def int_matmul(x, w_codes, w_scale, w_spec: QuantSpec, *, packed: bool = False,
 
     packed=False: w_codes (K, N) int8 — 1 byte/weight HBM reads.
     packed=True:  w_codes (K//2, N) int8 nibble-packed int4 pairs (see
-    core.quantizer.pack_int4) — 0.5 byte/weight, unpacked tile-wise in VMEM.
+    core.quantizer.pack_int4) — 0.5 byte/weight, unpacked tile-wise in VMEM,
+    with tiles chosen from the shape (quant_matmul.int4_tiles).
     """
     lead = x.shape[:-1]
     k = x.shape[-1]
     n = w_codes.shape[-1]
     x2 = x.reshape(-1, k)
-    bm, bn, bk = _qmm.DEFAULT_TILES
     if packed:
         assert w_codes.shape[0] * 2 == k, (x.shape, w_codes.shape)
-        bk = min(bk, k)
+        tiles = _qmm.int4_tiles(x2.shape[0], k, n)
+        bm, bn, bk = tiles
         x2p, m, _ = _pad2d(x2, bm, bk)
         pad_rows = (x2p.shape[1] - k) // 2
         pn = (-n) % bn
-        wp = jnp.pad(w_codes, ((0, pad_rows), (0, pn)))
+        wp = jnp.pad(w_codes, ((0, pad_rows), (0, pn)))  # none at int4_tiles
         ws = jnp.broadcast_to(jnp.asarray(w_scale, jnp.float32).reshape(1, -1),
                               (1, n))
         wsp = jnp.pad(ws, ((0, 0), (0, pn)), constant_values=1.0)
-        out = _qmm.int4_matmul(x2p, wp, wsp, interpret=interpret,
+        out = _qmm.int4_matmul(x2p, wp, wsp, tiles=tiles, interpret=interpret,
                                out_dtype=out_dtype)
         return out[:m, :n].reshape(*lead, n)
+    bm, bn, bk = _qmm.DEFAULT_TILES
     x2p, m, _ = _pad2d(x2, bm, bk)
     wp, _, _ = _pad2d(w_codes, bk, bn)
     ws = jnp.broadcast_to(jnp.asarray(w_scale, jnp.float32).reshape(1, -1), (1, n))

@@ -30,6 +30,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -868,17 +869,82 @@ def int_matmul(x, w_codes, w_col_scale, *, q_n_w: int, q_p_w: int,
     )(x, w_codes, w_col_scale.astype(jnp.float32))
 
 
+# int4 serving tiles are chosen from the shape (int4_tiles), not from
+# DEFAULT_TILES: a decode step reads every weight once, so each grid step
+# streams one large contiguous packed block.
+INT4_BK_MAX = 4096           # contraction rows per block (multiple of 256)
+INT4_BN_MAX = 2048           # output columns per block (multiple of 128)
+INT4_BM_MAX = 256            # activation rows per block
+INT4_VMEM_BUDGET = 16 << 20  # the default scoped VMEM limit of a v5e kernel
+
+
+def _int4_sub(bkp: int) -> int:
+    """Packed rows unpacked per dot inside a weight block: bounds the
+    unpacked temporaries while the block itself stays large."""
+    return 128 if bkp % 128 == 0 else bkp
+
+
+def _deinterleave(g: int):
+    """(g, g) 0/1 matrix: x @ P puts the even columns of each g-column group
+    of x first, then the odd ones."""
+    src = np.concatenate([np.arange(0, g, 2), np.arange(1, g, 2)])
+    return jnp.asarray(np.eye(g, dtype=np.float32)[:, src], jnp.bfloat16)
+
+
+def int4_vmem_bytes(bm: int, bn: int, bk: int) -> int:
+    """Upper bound on int4_matmul's VMEM at tiles (bm, bn, bk): the
+    double-buffered bf16 x block, packed weight block, scale row and f32
+    output block, the f32 accumulator, and one unpacked sub-block."""
+    return (2 * bm * bk * 2 + 2 * (bk // 2) * bn + 2 * 8 * bn * 4
+            + 2 * bm * bn * 4 + bm * bn * 4 + 8 * _int4_sub(bk // 2) * bn)
+
+
+def _divisor_tiles(d: int, unit: int, cap: int) -> list:
+    """Multiples of `unit` up to `cap` that divide d, largest first."""
+    return [t for t in range(cap - cap % unit, 0, -unit) if d % t == 0]
+
+
+def int4_tiles(m: int, k: int, n: int) -> tuple:
+    """(bm, bn, bk) for int4_matmul on an (m, k) x (k, n) product.
+
+    bm covers all m rows up to 256 (rounded up to bf16's 16 sublanes), so
+    a decode batch or a prefill chunk reads and unpacks each weight block
+    once. bk and bn are the largest tiles that divide k and n exactly, so
+    the packed weights are never padded (copied) on a call, shrunk (bn
+    first) until int4_vmem_bytes fits INT4_VMEM_BUDGET. Shapes without
+    such tiles (k not a multiple of 256, n not of 128) fall back to
+    DEFAULT_TILES and the ops wrapper pads.
+    """
+    bks = _divisor_tiles(k, 256, INT4_BK_MAX)
+    bns = _divisor_tiles(n, 128, INT4_BN_MAX)
+    if not bks or not bns:
+        bm, bn, bk = DEFAULT_TILES
+        return bm, bn, min(bk, k)
+    bm = min(-(-m // 16) * 16, INT4_BM_MAX)
+    return next((bm, bn, bk) for bk in bks for bn in bns
+                if int4_vmem_bytes(bm, bn, bk) <= INT4_VMEM_BUDGET)
+
+
 @functools.partial(jax.jit, static_argnames=("tiles", "interpret", "out_dtype"))
-def int4_matmul(x, w_packed, w_col_scale, *, tiles=DEFAULT_TILES,
-                interpret=None, out_dtype=jnp.float32):
+def int4_matmul(x, w_packed, w_col_scale, *, tiles, interpret=None,
+                out_dtype=jnp.float32):
     """Serving matmul over NIBBLE-PACKED int4 weight codes.
 
     w_packed: (K//2, N) int8, byte p holding code row 2p in the low nibble and
     row 2p+1 in the high nibble (two's complement, so any bits<=4 code fits).
-    HBM reads 0.5 byte/weight — half of int_matmul, a quarter of bf16 — and
-    the unpack (shift/sign-extend/interleave) happens on the VMEM tile.
+    HBM reads 0.5 byte/weight — half of int_matmul, a quarter of bf16.
 
-    K must be even and a multiple of 2*... the ops wrapper pads to tiles.
+    No interleave: x @ W = x[:, 0::2] @ W_lo + x[:, 1::2] @ W_hi, where W_lo
+    and W_hi are the sign-extended nibbles as they lie in the packed block,
+    converted straight to bf16 (exact). The wrapper de-interleaves x's
+    columns within each group of 2*sub by one 0/1 permutation matmul on the
+    MXU (exact: one nonzero term per output; a lane-strided slice of x is
+    several times slower on the TPU), so each sub-block's even and odd x
+    columns are contiguous. The column scale multiplies the f32 accumulator
+    once per output tile, after the last K step.
+
+    `tiles` come from int4_tiles; the grid must tile x and w_packed exactly
+    (ops.int_matmul pads where the fallback tiles need it).
     """
     m, k = x.shape
     kp, n = w_packed.shape
@@ -887,32 +953,39 @@ def int4_matmul(x, w_packed, w_col_scale, *, tiles=DEFAULT_TILES,
     bn = min(tiles[1], n)
     bk = min(tiles[2], k)
     assert bk % 2 == 0, bk
+    bkp = bk // 2
+    sub = _int4_sub(bkp)
     grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), pl.cdiv(k, bk))
 
     def kernel(x_ref, c_ref, ws_ref, o_ref, acc_ref):
         @pl.when(pl.program_id(2) == 0)
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
-        b32 = c_ref[...].astype(jnp.int32)             # (bk//2, bn) bytes
-        lo = (b32 << 28) >> 28                         # sign-extended nibbles
-        hi = (b32 << 24) >> 28
-        codes = jnp.stack([lo, hi], axis=1).reshape(bk, b32.shape[1])
-        wd = (codes.astype(jnp.float32)
-              * jnp.maximum(ws_ref[...].astype(jnp.float32), 1e-9)
-              ).astype(jnp.bfloat16)
-        acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.bfloat16), wd,
-                                preferred_element_type=jnp.float32)
+        acc = acc_ref[...]
+        for r in range(0, bkp, sub):
+            b32 = c_ref[r:r + sub, :].astype(jnp.int32)
+            lo = ((b32 << 28) >> 28).astype(jnp.float32).astype(jnp.bfloat16)
+            hi = (b32 >> 4).astype(jnp.float32).astype(jnp.bfloat16)
+            acc += jnp.dot(x_ref[:, 2 * r:2 * r + sub], lo,
+                           preferred_element_type=jnp.float32)
+            acc += jnp.dot(x_ref[:, 2 * r + sub:2 * (r + sub)], hi,
+                           preferred_element_type=jnp.float32)
+        acc_ref[...] = acc
 
         @pl.when(pl.program_id(2) == grid[2] - 1)
         def _done():
-            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+            o_ref[...] = (acc_ref[...] * jnp.maximum(ws_ref[...], 1e-9)
+                          ).astype(o_ref.dtype)
 
+    g = 2 * sub
+    xp = jnp.dot(x.astype(jnp.bfloat16).reshape(m * k // g, g),
+                 _deinterleave(g), preferred_element_type=jnp.float32)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk // 2, bn), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((bkp, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
@@ -920,4 +993,5 @@ def int4_matmul(x, w_packed, w_col_scale, *, tiles=DEFAULT_TILES,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         name="int4_matmul",
         interpret=resolve_interpret(interpret),
-    )(x, w_packed, w_col_scale.astype(jnp.float32))
+    )(xp.astype(jnp.bfloat16).reshape(m, k), w_packed,
+      w_col_scale.astype(jnp.float32))

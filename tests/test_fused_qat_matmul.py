@@ -324,17 +324,64 @@ def test_pack_int4_odd_axis_raises():
         pack_int4(jnp.zeros((5, 4), jnp.int8), 0)
 
 
-@pytest.mark.parametrize("mkn", [(33, 80, 56), (5, 130, 300)])
+_GRANITE_KN = [(4096, 1024), (4096, 14336), (14336, 4096)]  # k/v, gate/up, down
+_INT4_CASES = ([(33, 80, 56, "normal"), (5, 130, 300, "normal")]
+               + [(m, k, n, "normal") for k, n in _GRANITE_KN for m in (1, 32, 256)]
+               + [(32, 4096, 1024, "clamp")])
+
+
+@pytest.mark.parametrize(
+    "mkn", _INT4_CASES,
+    ids=["mkn0", "mkn1"] + [f"granite-{k}x{n}-m{m}" for m, k, n, _ in
+                            _INT4_CASES[2:-1]] + ["scale-clamp"])
 def test_packed_int4_matmul_matches_int8(rng, mkn):
-    m, k, n = mkn
+    """Packed int4 serving matmul vs the int8 reference, at odd shapes (the
+    fallback tiles) and at granite-8b's shapes (the streaming tiles).
+
+    The reference rounds each dequantized weight code*scale to bf16; the
+    kernel keeps the codes exact and scales the f32 sum. So the kernel must
+    match the exact product of the bf16 inputs closely, and differ from the
+    reference by no more than bf16's unit roundoff (2^-8) of |x| @ |w|.
+    """
+    m, k, n, scale = mkn
     wspec = QuantSpec(bits=4)
     x = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
     codes = jnp.asarray(rng.integers(-8, 8, (k, n)), jnp.int8)
-    ws = jnp.asarray(np.abs(rng.standard_normal(n)) * 0.05 + 0.01, jnp.float32)
+    if scale == "clamp":   # at, above and below the 1e-9 floor, and zero
+        ws = jnp.asarray(rng.choice([0.0, 2.5e-10, 1e-9, 4e-9], n), jnp.float32)
+    else:
+        ws = jnp.asarray(np.abs(rng.standard_normal(n)) * 0.05 + 0.01,
+                         jnp.float32)
     want = ref.int_matmul(x, codes, ws.reshape(1, -1), q_n_w=8, q_p_w=7)
     got = ops.int_matmul(x, pack_int4(codes, 0), ws, wspec, packed=True,
                          interpret=True)
-    assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-2, atol=2e-3)
+    xb = x.astype(jnp.bfloat16).astype(jnp.float32)
+    w = codes.astype(jnp.float32) * jnp.maximum(ws, 1e-9)
+    hi = jax.lax.Precision.HIGHEST
+    exact = jnp.dot(xb, w, precision=hi)
+    absprod = jnp.dot(jnp.abs(xb), jnp.abs(w), precision=hi)
+    assert got.shape == (m, n)
+    assert (jnp.abs(got - exact) <= 2.0 ** -12 * absprod).all()
+    assert (jnp.abs(got - want) <= (2.0 ** -8 + 2.0 ** -12) * absprod).all()
+
+
+@pytest.mark.parametrize("arch", ["granite-8b", "qwen1.5-0.5b"])
+@pytest.mark.parametrize("m", [32, 256])
+def test_int4_tiles_divide_published_linears(arch, m):
+    """Every packed linear (k, n) of the model gets tiles that divide it, so
+    no call pads (copies) the weights, within the kernel's VMEM budget;
+    the QAT kernels' DEFAULT_TILES stay as they were."""
+    from repro.configs.registry import get_config
+    from repro.kernels import quant_matmul as qmm
+    cfg = get_config(arch)
+    d, f = cfg.d_model, cfg.d_ff
+    q, kv = cfg.n_heads * cfg.head_dim_, cfg.n_kv_heads * cfg.head_dim_
+    for k, n in [(d, q), (d, kv), (q, d), (d, f), (f, d)]:
+        bm, bn, bk = qmm.int4_tiles(m, k, n)
+        assert k % bk == 0 and n % bn == 0, (k, n, bk, bn)
+        assert bm == m  # one row block: each weight block is read once
+        assert qmm.int4_vmem_bytes(bm, bn, bk) <= qmm.INT4_VMEM_BUDGET
+    assert qmm.DEFAULT_TILES == (128, 128, 512)
 
 
 def test_convert_to_serving_packs_low_bits(key):

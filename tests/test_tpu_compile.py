@@ -27,6 +27,7 @@ from repro.kernels.decode_attention import pooled_decode_attention
 D_MODEL, HEADS, HEAD_DIM, D_FF, VOCAB = 1024, 16, 64, 2816, 151936
 TOKENS = 2048          # one training batch: 4 x 512
 SLOTS, MAX_LEN = 4, 2048
+SLOTS_DECODE, CHUNK = 32, 256  # a pooled decode step; a prefill chunk
 A4 = QuantSpec(bits=4, signed=False, offset=True)
 W4 = QuantSpec(bits=4, signed=True)
 A8 = QuantSpec(bits=8, signed=False, offset=True)
@@ -150,12 +151,19 @@ def test_batched_expert_backward_compiles(one_chip):
              s((e, 512), jnp.float32))
 
 
-@pytest.mark.parametrize("packed", [False, True], ids=["int8", "int4"])
-def test_int_matmul_compiles(one_chip, packed):
+@pytest.mark.parametrize("packed,tokens", [(False, TOKENS), (True, TOKENS),
+                                           (True, SLOTS_DECODE), (True, CHUNK)],
+                         ids=["int8", "int4", "int4-decode", "int4-prefill"])
+def test_int_matmul_compiles(one_chip, packed, tokens):
+    """int4 at a pooled decode step and a prefill chunk compiles the tiles
+    int4_tiles picks for them, with the packed weights passed unpadded."""
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     rows = D_MODEL // 2 if packed else D_MODEL
     spec = W4 if packed else W8
-    _compile(lambda x, c, sc: ops.int_matmul(x, c, sc, spec, packed=packed,
-                                             interpret=False),
-             s((TOKENS, D_MODEL), jnp.bfloat16), s((rows, D_FF), jnp.int8),
-             s((D_FF,), jnp.float32))
+    text = _compile(lambda x, c, sc: ops.int_matmul(x, c, sc, spec,
+                                                    packed=packed,
+                                                    interpret=False),
+                    s((tokens, D_MODEL), jnp.bfloat16),
+                    s((rows, D_FF), jnp.int8), s((D_FF,), jnp.float32))
+    if packed:
+        assert " pad(" not in text
