@@ -128,8 +128,12 @@ def run_training(cfg, qcfg, tcfg: TrainConfig, dcfg: DataConfig, *,
     # restore/rollback at label s resumes at s + 1 (the data stream is
     # (step, host)-keyed, so the replay is identical).
     i = start if start == 0 else start + 1
+    # which mesh and which matmul path the step ran: a trace says so itself
+    step_args = dict(data=mesh.shape["data"], model=mesh.shape["model"],
+                     fused_matmul=run_qcfg.fused_matmul)
     while i < steps:
-        with jax.profiler.StepTraceAnnotation(tracing.TRAIN_STEP, step_num=i):
+        with jax.profiler.StepTraceAnnotation(tracing.TRAIN_STEP, step_num=i,
+                                              **step_args):
             if on_step is not None:
                 with tracing.span(tracing.TRAIN_HOOK):
                     injected = on_step(i, state)

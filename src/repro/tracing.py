@@ -9,7 +9,7 @@ shows and groups by; free at run time.
 
 | Span or scope | In | Covers | Read by |
 | --- | --- | --- | --- |
-| `train.step` | `run_training` | one loop iteration (`step_num`) | xprof steps, `host_syncs.train` |
+| `train.step` | `run_training` | one loop iteration (`step_num`; the mesh's `data`, `model` and the resolved `fused_matmul`) | xprof steps, `host_syncs.train` |
 | `train.hook` | `run_training` | the `on_step` hook | breakdown |
 | `train.input` | `run_training` | `sample_batch`, KD labels | `train_input_idle_ms` |
 | `train.dispatch` | `run_training` | the `step_fn` call | `host_syncs.train` |

@@ -5,8 +5,10 @@ TPU's lowering rules (block-shape tiling, scalar stores to VMEM, scoped VMEM
 limits). Here each kernel is compiled with `interpret=False` for a v5e chip
 that libtpu describes but that is not attached, at the published widths of
 qwen1.5-0.5b (d_model 1024, 16 heads of 64, d_ff 2816, vocab 151936), and
-the compiled program must hold the kernel (`tpu_custom_call`). Nothing runs,
-so this says nothing about results or times.
+the compiled program must hold the kernel (`tpu_custom_call`). One whole
+sharded train step, Granite-8B's `qat-granite-8b-2x2` cell, compiles over
+the four described chips and must fit their memory. Nothing runs, so this
+says nothing about results or times.
 
 The topology is described inside a module fixture, never at import: only
 one process may hold libtpu, and under pytest-xdist every worker imports
@@ -167,3 +169,62 @@ def test_int_matmul_compiles(one_chip, packed, tokens):
                     s((rows, D_FF), jnp.int8), s((D_FF,), jnp.float32))
     if packed:
         assert " pad(" not in text
+
+
+def test_granite_sharded_train_step_fits_a_2x2_host(topo):
+    """The QAT step of the `qat-granite-8b-2x2` cell (Granite-8B widths at
+    the configuration's depth, its batch and length, the (data, model) mesh
+    and rules `run_training` builds) compiles over the four described chips
+    and leaves each at least 0.5 GB of v5e's 15.75 (14.62 GB when written)."""
+    import json
+
+    from repro.configs.registry import get_config
+    from repro.core.policy import get_preset
+    from repro.dist import sharding as shard
+    from repro.launch.mesh import kernels_for_mesh, make_host_mesh
+    from repro.train.sentinel import SentinelConfig
+    from repro.train.state import TrainConfig, init_state
+    from repro.train.train_step import make_train_step
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    c = json.load(open(os.path.join(root, "bench", "configs",
+                                    "granite-8b-qat-w4a4.json")))
+    tr = json.load(open(os.path.join(root, "bench", "traffic",
+                                     "qat_b4_s1024_2x2.json")))
+    cfg = get_config(c["arch"]).replace(n_layers=c["num_hidden_layers"])
+    assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_ff,
+            cfg.vocab_size, cfg.tie_embeddings) == (
+        c["hidden_size"], c["num_attention_heads"],
+        c["num_key_value_heads"], c["intermediate_size"], c["vocab_size"],
+        c["tie_word_embeddings"])
+    qcfg = get_preset(c["quant"]["preset"])
+    tcfg = TrainConfig(total_steps=c["train"]["total_steps"], kd="mckd",
+                       kd_topk=c["train"]["kd_topk"],
+                       sentinel=SentinelConfig())
+    mesh = make_host_mesh(model=tr["model_parallel"], devices=topo.devices)
+    like = jax.eval_shape(lambda k: init_state(k, cfg, qcfg, tcfg),
+                          jax.random.PRNGKey(0))
+    sh = shard.named_tree(shard.state_pspecs(like, mesh, qcfg), mesh)
+    state = jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=s), like, sh)
+    rep = NamedSharding(mesh, P())
+    b, s, k = tr["batch"], tr["seq_len"], c["train"]["kd_topk"]
+    batch = {"tokens": jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=rep),
+             "labels": jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=rep),
+             "kd_idx": jax.ShapeDtypeStruct((b, s, k), jnp.int32,
+                                            sharding=rep),
+             "kd_p": jax.ShapeDtypeStruct((b, s, k), jnp.float32,
+                                          sharding=rep)}
+    constrain, logits_constrain = shard.make_constrains(mesh)
+    step = jax.jit(make_train_step(cfg, kernels_for_mesh(qcfg, mesh), tcfg,
+                                   constrain=constrain,
+                                   logits_constrain=logits_constrain),
+                   in_shardings=(sh, None), out_shardings=(sh, None),
+                   donate_argnums=0)
+    compiled = step.lower(state, batch).compile()
+    assert "tpu_custom_call" not in compiled.as_text()   # jnp path on a mesh
+    ma = compiled.memory_analysis()
+    per_device = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                  - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert per_device <= 15.25e9, per_device
